@@ -27,19 +27,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import morton
 from .cuboid import CuboidGrid
 
-try:  # jax >= 0.6: public jax.shard_map with the check_vma kwarg
-    _public_shard_map = jax.shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _public_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-except AttributeError:  # jax 0.4.x: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _experimental_sm
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _experimental_sm(f, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_rep=False)
-
 
 def pack_to_cuboids(volume: np.ndarray, grid: CuboidGrid) -> np.ndarray:
     """Dense volume -> (n_cells, *cuboid_shape), rows in Morton order.
@@ -136,22 +123,26 @@ def distributed_cutout(packed: jax.Array, grid: CuboidGrid,
         return jax.lax.all_gather(picked, axis)            # (n_dev,max_k,*cs)
 
     gathered = jax.jit(
-        _shard_map(gather_local, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs)
+        jax.shard_map(gather_local, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
     )(packed, local_idx_j)                                 # replicated
 
     flat = gathered.reshape((n_dev * max_k,) + tuple(cs))
     ordered = jnp.take(flat, jnp.asarray(slot_of), axis=0)  # box-grid order
-    blocks = ordered.reshape(tuple(gshape) + tuple(cs))
-    # interleave grid and intra-cuboid axes: (g0,c0,g1,c1,...) then merge
-    rank = len(cs)
-    perm = []
-    for d in range(rank):
-        perm += [d, rank + d]
+    return merge_blocks(ordered.reshape(tuple(gshape) + tuple(cs)), lo, hi)
+
+
+def merge_blocks(blocks: jax.Array, lo: Sequence[int],
+                 hi: Sequence[int]) -> jax.Array:
+    """Cuboids in box-grid order, shaped (*gshape, *cuboid_shape) -> the
+    dense [lo, hi) box. Interleaves grid and intra-cuboid axes
+    (g0, c0, g1, c1, ...), merges each pair, then trims to the box."""
+    rank = blocks.ndim // 2
+    gshape, cs = blocks.shape[:rank], blocks.shape[rank:]
+    perm = [i for d in range(rank) for i in (d, rank + d)]
     merged = blocks.transpose(perm).reshape(
         tuple(g * c for g, c in zip(gshape, cs)))
-    glo = [l // c * c for l, c in zip(lo, cs)]
-    trim = tuple(slice(l - a, h - a) for l, h, a in zip(lo, hi, glo))
+    trim = tuple(slice(l % c, l % c + h - l) for l, h, c in zip(lo, hi, cs))
     return merged[trim]
 
 
@@ -222,8 +213,8 @@ def distributed_write_cutout(packed: jax.Array, grid: CuboidGrid,
         return jax.lax.fori_loop(0, dblk.shape[0], body, shard)
 
     updated = jax.jit(
-        _shard_map(apply_local, mesh=mesh,
-                   in_specs=(pspec, rep, rep, rep, rep),
-                   out_specs=pspec)
+        jax.shard_map(apply_local, mesh=mesh,
+                      in_specs=(pspec, rep, rep, rep, rep),
+                      out_specs=pspec, check_vma=False)
     )(packed, dblocks, mblocks, cells_j, seg_starts)
     return updated

@@ -9,51 +9,41 @@ plan (cell index per box-grid position) is a *scalar-prefetched* operand
 index_map before the DMA is issued — exactly a database fetching the block
 list from its spatial index (C7) and then streaming blocks.
 
-Alignment shows up structurally: cuboid-aligned cutouts copy whole (8,128)-
-tiled blocks; unaligned ones round up and trim (the wrapper does this),
-paying the read-amplification the paper measures in Fig 10.
+The kernel writes whole cuboids out cuboid-major, one block per grid step;
+interleaving them into the dense box is left to XLA (ops.py), as
+``distributed_cutout`` does. Blocks are copied in the (cx, cz, cy) view of
+each cuboid: with the paper's 128x128x16 cuboids the short z extent would
+otherwise sit on the 128-wide lane axis. XLA already stores the
+cuboid-major array with y minor, so the view is a bitcast, not a copy.
 """
 from __future__ import annotations
-
-from typing import Tuple
 
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(plan_ref, packed_ref, out_ref):
-    del plan_ref  # consumed by the index maps
-    out_ref[...] = packed_ref[...]
-
-
-def cutout_gather_kernel(packed, plan, gshape: Tuple[int, ...],
-                         interpret: bool = False):
+def cutout_gather_kernel(packed, plan, interpret: bool = False):
     """packed: (n_cells, cx, cy, cz); plan: (n_box,) int32 cell per box-grid
-    position (row-major). Returns (gx*cx, gy*cy, gz*cz)."""
+    position. Returns the picked cuboids, (n_box, cx, cy, cz)."""
     n_cells, cx, cy, cz = packed.shape
-    gx, gy, gz = gshape
-    n_box = gx * gy * gz
-    assert plan.shape == (n_box,)
-
-    def in_map(g, plan_ref):
-        return plan_ref[g], 0, 0, 0
-
-    def out_map(g, plan_ref):
-        # row-major decode of the box-grid position
-        return g // (gy * gz), (g // gz) % gy, g % gz
-
+    (n_box,) = plan.shape
+    block = (1, cx, cz, cy)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_box,),
-        in_specs=[pl.BlockSpec((1, cx, cy, cz), in_map)],
-        out_specs=pl.BlockSpec((cx, cy, cz), out_map),
+        in_specs=[pl.BlockSpec(block, lambda g, plan_ref: (plan_ref[g], 0, 0,
+                                                           0))],
+        out_specs=pl.BlockSpec(block, lambda g, plan_ref: (g, 0, 0, 0)),
     )
-    out_shape = jax.ShapeDtypeStruct((gx * cx, gy * cy, gz * cz),
-                                     packed.dtype)
 
     def _kern(plan_ref, packed_ref, out_ref):
-        out_ref[...] = packed_ref[0]
+        del plan_ref  # consumed by the index maps
+        out_ref[...] = packed_ref[...]
 
-    return pl.pallas_call(_kern, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(plan, packed)
+    picked = pl.pallas_call(
+        _kern, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_box, cx, cz, cy), packed.dtype),
+        interpret=interpret,
+    )(plan, packed.transpose(0, 1, 3, 2))
+    return picked.transpose(0, 1, 3, 2)

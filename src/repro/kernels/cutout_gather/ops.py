@@ -1,6 +1,7 @@
-"""jit'd cutout wrapper: box -> Morton plan -> gather kernel -> trim."""
+"""jit'd cutout wrapper: box -> Morton plan -> gather kernel -> merge."""
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import jax
@@ -9,6 +10,7 @@ import numpy as np
 
 from ...core import morton
 from ...core.cuboid import CuboidGrid
+from ...core.distributed import merge_blocks
 from .kernel import cutout_gather_kernel
 
 
@@ -29,13 +31,21 @@ def build_plan(grid: CuboidGrid, lo: Sequence[int], hi: Sequence[int]):
     return gshape, cells, [g * c for g, c in zip(glo, cs)]
 
 
+@functools.partial(jax.jit, static_argnames=("gshape", "lo", "hi",
+                                             "interpret"))
+def _gather(packed, cells, *, gshape, lo, hi, interpret):
+    picked = cutout_gather_kernel(packed, cells, interpret=interpret)
+    return merge_blocks(picked.reshape(gshape + packed.shape[1:]), lo, hi)
+
+
 def cutout_gather(packed, grid: CuboidGrid, lo, hi, *, interpret=None):
     """Dense cutout [lo, hi) from a cuboid-major device array."""
     lo = tuple(int(x) for x in lo)
     hi = tuple(int(x) for x in hi)
     interpret = _interpret_default() if interpret is None else interpret
     gshape, cells, alo = build_plan(grid, lo, hi)
-    merged = cutout_gather_kernel(packed, jnp.asarray(cells), gshape,
-                                  interpret=interpret)
-    trim = tuple(slice(l - a, h - a) for l, h, a in zip(lo, hi, alo))
-    return merged[trim]
+    # only the box's offset within its first cuboid shapes the program
+    return _gather(packed, jnp.asarray(cells), gshape=gshape,
+                   lo=tuple(l - a for l, a in zip(lo, alo)),
+                   hi=tuple(h - a for h, a in zip(hi, alo)),
+                   interpret=interpret)

@@ -60,7 +60,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q.astype(jnp.float32) * scale, k.astype(jnp.float32),
-            (((1,), (1,)), ((), ()))).reshape(G, block_q, block_kv)
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).reshape(G, block_q, block_kv)
         mask = jnp.ones((block_q, block_kv), jnp.bool_)
         if causal:
             mask &= q_pos >= k_pos
@@ -76,10 +77,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         corr = jnp.exp(m_prev - m_new)
         l_sc[...] = l_prev * corr + p.sum(axis=-1)
         m_sc[...] = m_new
+        # the MXU accumulates in f32 whatever the operand dtype
         pv = jax.lax.dot_general(
             p.reshape(G * block_q, block_kv).astype(v.dtype), v,
-            (((1,), (0,)), ((), ()))).reshape(G, block_q, D)
-        acc_sc[...] = acc_sc[...] * corr[..., None] + pv.astype(jnp.float32)
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).reshape(G, block_q, D)
+        acc_sc[...] = acc_sc[...] * corr[..., None] + pv
 
     @pl.when(kj == n_kv - 1)
     def _finish():

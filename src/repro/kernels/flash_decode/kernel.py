@@ -38,7 +38,8 @@ def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 
     q = q_ref[0, 0].astype(F32) * scale                  # (G, D)
     kb = k_ref[0, 0].astype(F32)                         # (Bkv, D)
-    s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())))  # (G, Bkv)
+    s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                            preferred_element_type=F32)  # (G, Bkv)
 
     n_valid = lens_ref[b]
     pos = j * block_kv + jax.lax.broadcasted_iota(jnp.int32, (G, block_kv),
@@ -53,9 +54,11 @@ def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
     l_sc[...] = l_prev * corr + p.sum(axis=-1, keepdims=True)
     m_sc[...] = m_new
     vb = v_ref[0, 0]                                     # (Bkv, D)
+    # the MXU accumulates in f32 whatever the operand dtype
     pv = jax.lax.dot_general(p.astype(vb.dtype), vb,
-                             (((1,), (0,)), ((), ())))   # (G, D)
-    acc_sc[...] = acc_sc[...] * corr + pv.astype(F32)
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=F32)   # (G, D)
+    acc_sc[...] = acc_sc[...] * corr + pv
 
     @pl.when(j == n_kv - 1)
     def _finish():

@@ -1,11 +1,13 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST precede any jax import (jax locks the device count
-on first init); this module is the only place the 512 placeholder devices
-exist — tests and benches see the single real CPU device.
+The lines above MUST precede any jax import (jax locks the device count on
+first init). Run as a program, this module is the only place the 512
+placeholder devices exist; importing it (as hillclimb does) leaves the
+importer's devices alone, so tests and benches see the real CPU device.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch smollm-135m \

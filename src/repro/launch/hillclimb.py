@@ -1,6 +1,7 @@
 import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=512")
+if __name__ == "__main__":  # before any jax import; importers keep theirs
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
 
 """Perf hillclimbing harness: re-lower a dry-run cell under a named
 variant (config / sharding-rule / loss changes), re-derive the roofline

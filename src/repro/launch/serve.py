@@ -1,7 +1,10 @@
 """Batched serving driver: prefill + decode loop.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --smoke \
-      --batch 4 --prompt-len 16 --gen 16
+  PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
+      --batch 8 --prompt-len 128 --gen 32
+
+The mesh is built from the devices present (1x1 on one chip, 2x2 on four);
+``--smoke`` selects the reduced per-arch config.
 """
 from __future__ import annotations
 
@@ -17,7 +20,9 @@ from ..models import build_model, init_params
 from ..models.params import ParamSpec
 from ..serve import make_serve_step
 from ..train import make_plan, use_plan
-from .mesh import make_local_mesh, make_production_mesh
+from ..train.sharding import resolve_shardings
+from .cache import enable_compile_cache
+from .mesh import make_device_mesh
 
 
 def zero_cache(model, cfg, B, cache_len):
@@ -30,7 +35,15 @@ def zero_cache(model, cfg, B, cache_len):
         is_leaf=lambda x: isinstance(x, ParamSpec))
 
 
+def _device_label() -> str:
+    d = jax.devices()[0]
+    return f"{d.platform} {d.device_kind} x{len(jax.devices())}"
+
+
 def main(argv=None):
+    """Returns ``{"tokens", "logits"}`` for the batched run (generated
+    tokens (B, gen) and the last step's logits (B, 1, V)), or
+    ``{"finished", "occupancy"}`` with ``--continuous``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -41,12 +54,15 @@ def main(argv=None):
                     help="slot-based continuous batching engine")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(
         args.arch)
-    mesh = make_local_mesh() if args.smoke else make_production_mesh()
+    mesh = make_device_mesh()
     plan = make_plan(mesh)
     model = build_model(cfg)
-    params = init_params(model.specs(), jax.random.key(0))
+    specs = model.specs()
+    params = jax.device_put(init_params(specs, jax.random.key(0)),
+                            resolve_shardings(specs, plan))
     serve_step = jax.jit(make_serve_step(model, cfg))
 
     B = args.batch
@@ -68,15 +84,15 @@ def main(argv=None):
         total = sum(len(v) for v in done.values())
         print(f"continuous batching: {len(done)} requests over {B} slots")
         print(f"occupancy {eng.occupancy:.2f}, "
-              f"{total / dt:.1f} gen tok/s (CPU, smoke scale)")
-        return done
+              f"{total / dt:.1f} gen tok/s ({_device_label()}, "
+              f"compile included)")
+        return {"finished": done, "occupancy": eng.occupancy}
     prompts = jnp.asarray(rng.integers(0, cfg.vocab,
                                        size=(B, args.prompt_len)),
                           jnp.int32)
     cache = zero_cache(model, cfg, B, cache_len)
     with use_plan(plan):
         # prefill by stepping the prompt (batched requests share steps)
-        tok = prompts[:, :1]
         t0 = time.perf_counter()
         for i in range(args.prompt_len):
             nxt, logits, cache = serve_step(params, cache,
@@ -93,9 +109,10 @@ def main(argv=None):
     out = jnp.concatenate(generated, axis=1)
     total_tokens = B * (args.prompt_len + args.gen - 1)
     print(f"served {B} sequences, {args.gen} new tokens each")
-    print(f"throughput {total_tokens / dt:.1f} tok/s (CPU, smoke scale)")
+    print(f"throughput {total_tokens / dt:.1f} tok/s ({_device_label()}, "
+          f"compile included)")
     print("sample:", np.asarray(out[0])[:12].tolist())
-    return out
+    return {"tokens": out, "logits": logits}
 
 
 if __name__ == "__main__":

@@ -1,11 +1,11 @@
 """End-to-end training driver.
 
   PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
-      --smoke --steps 50 --ckpt-dir /tmp/ckpt
+      --steps 5 --batch 8 --seq-len 2048
 
-``--smoke`` selects the reduced per-arch config so the driver runs on one
-CPU device; the same code path drives the production mesh on real hardware
-(mesh selection + plan resolution are config, not code).
+The mesh is built from the devices present (1x1 on one chip, 2x2 on four)
+and parameters, optimizer state and batches are placed by the sharding
+plan resolved against it; ``--smoke`` selects the reduced per-arch config.
 
 The driver wires every substrate together: Morton-sharded data pipeline ->
 jit'd train_step under the sharding plan -> async cuboid-chunked
@@ -20,14 +20,17 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from ..configs import get_config, get_smoke_config
 from ..data import DataPipeline, PipelineConfig, TokenStore
 from ..ft import FailureInjector, StragglerMonitor, TrainingSupervisor
 from ..models import build_model, init_params
 from ..optim import AdamWConfig, adamw_init_specs
-from ..train import make_train_step, use_plan, make_plan
-from .mesh import make_local_mesh, make_production_mesh
+from ..train import batch_pspec, make_train_step, use_plan, make_plan
+from ..train.sharding import resolve_shardings
+from .cache import enable_compile_cache
+from .mesh import make_device_mesh
 
 
 def init_opt_state(model_specs, rng):
@@ -59,7 +62,8 @@ def synthetic_corpus(cfg, n_docs=256, doc_len=1024, seed=0) -> TokenStore:
     return store
 
 
-def main(argv=None) -> Dict:
+def main(argv=None, mesh=None) -> Dict:
+    """``mesh`` overrides the mesh built from the devices present."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -75,11 +79,16 @@ def main(argv=None) -> Dict:
                     choices=["none", "bf16", "int8"])
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(
         args.arch)
-    mesh = make_local_mesh() if args.smoke else make_production_mesh()
+    mesh = make_device_mesh() if mesh is None else mesh
     plan = make_plan(mesh)
     model, params, opt = build_state(cfg)
+    specs = model.specs()
+    params = jax.device_put(params, resolve_shardings(specs, plan))
+    opt = jax.device_put(opt, resolve_shardings(adamw_init_specs(specs),
+                                                plan))
     opt_cfg = AdamWConfig(lr_peak=args.lr, warmup_steps=5,
                           total_steps=args.steps,
                           grad_compression=args.grad_compression)
@@ -98,7 +107,9 @@ def main(argv=None) -> Dict:
         params, opt = state
         t0 = time.perf_counter()
         batch = pipe.get_batch(step)
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+        batch = {k: jax.device_put(v, NamedSharding(
+            mesh, batch_pspec(plan, v.ndim, v.shape[0])))
+            for k, v in batch.items()}
         if cfg.frontend == "patch_stub":
             B = batch["tokens"].shape[0]
             rng = np.random.default_rng(step)

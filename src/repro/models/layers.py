@@ -102,7 +102,11 @@ def _block_attn_update(q, k, v, m, l, acc, mask):
     """One online-softmax update. q:(...,Bq,D) k/v:(...,Bkv,D)
     mask:(...,Bq,Bkv) additive; m,l:(...,Bq); acc:(...,Bq,Dv)."""
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(F32) + mask
-    m_new = jnp.maximum(m, s.max(axis=-1))
+    # The running max only keeps exp() in range: the result does not depend
+    # on it, so no gradient flows through it. Differentiating the max would
+    # divide by the count of scores equal to it, which the TPU can make 0
+    # (bf16 scores recomputed with another rounding): NaN gradients.
+    m_new = jax.lax.stop_gradient(jnp.maximum(m, s.max(axis=-1)))
     p = jnp.exp(s - m_new[..., None])
     correction = jnp.exp(m - m_new)
     l_new = l * correction + p.sum(axis=-1)

@@ -132,34 +132,46 @@ class Detection:
     confidence: float
 
 
-def detect_synapses(vol: np.ndarray, threshold: float = 2.0,
-                    min_voxels: int = 8, max_voxels: int = 512,
-                    exclusion_mask: Optional[np.ndarray] = None
-                    ) -> Tuple[List[Detection], np.ndarray]:
-    """Detect synapse-like blobs in one cutout. Returns detections + labels."""
+def synapse_mask(vol, threshold: float = 2.0,
+                 exclusion_mask: Optional[np.ndarray] = None):
+    """DoG response z-scored over the cutout, and its thresholded mask."""
     x = jnp.asarray(vol, dtype=jnp.float32)
     resp = difference_of_gaussians(x)
     resp = (resp - resp.mean()) / (resp.std() + 1e-6)
     mask = resp > threshold
     if exclusion_mask is not None:
         mask = jnp.logical_and(mask, ~jnp.asarray(exclusion_mask))
+    return resp, mask
+
+
+def detect_synapses(vol: np.ndarray, threshold: float = 2.0,
+                    min_voxels: int = 8, max_voxels: int = 512,
+                    exclusion_mask: Optional[np.ndarray] = None
+                    ) -> Tuple[List[Detection], np.ndarray]:
+    """Detect synapse-like blobs in one cutout. Returns detections + labels."""
+    resp, mask = synapse_mask(vol, threshold, exclusion_mask)
     labels = np.asarray(connected_components(mask))
     dets: List[Detection] = []
     out_labels = np.zeros_like(labels)
     resp_np = np.asarray(resp)
     next_id = 1
-    for lab in np.unique(labels):
-        if lab == 0:
-            continue
-        where = np.argwhere(labels == lab)
-        n = len(where)
+    # group foreground voxels by label with one stable sort: each group
+    # lists its voxels in C order, as np.argwhere(labels == lab) would,
+    # without a whole-volume scan per label
+    fg = np.flatnonzero(labels)
+    order = np.argsort(labels.ravel()[fg], kind="stable")
+    labs, starts, sizes = np.unique(labels.ravel()[fg][order],
+                                    return_index=True, return_counts=True)
+    for lab, start, n in zip(labs, starts, sizes):
         if not (min_voxels <= n <= max_voxels):
             continue  # too small = noise; too big = not a synapse (§3.1)
+        where = np.stack(np.unravel_index(fg[order[start:start + n]],
+                                          labels.shape), axis=1)
         lo = where.min(axis=0)
         hi = where.max(axis=0) + 1
         conf = float(1.0 / (1.0 + np.exp(
             -resp_np[tuple(where.T)].mean())))
-        dets.append(Detection(tuple(where.mean(axis=0)), n,
+        dets.append(Detection(tuple(where.mean(axis=0)), int(n),
                               tuple(int(v) for v in lo),
                               tuple(int(v) for v in hi), conf))
         out_labels[tuple(where.T)] = next_id

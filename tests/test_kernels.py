@@ -1,7 +1,9 @@
 """Per-kernel allclose vs pure-jnp oracles, swept over shapes/dtypes.
 
-All kernels execute in interpret mode on CPU; on TPU the same code paths
-compile via Mosaic (interpret=None auto-detects backend).
+All kernels execute in interpret mode on CPU (interpret=None picks it off
+the TPU). Interpret mode checks results, not whether Mosaic accepts a
+kernel: tests/test_tpu_compile.py compiles the main-path kernels for a
+described TPU v5e, and chip_smoke.py runs them compiled on the chip.
 """
 import jax
 import jax.numpy as jnp
@@ -71,6 +73,26 @@ def test_flash_attention_matches_blockwise_jnp():
                             block_q=32, block_kv=64)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
                                rtol=2e-5)
+
+
+@pytest.mark.parametrize("skip", [True, False])
+def test_blockwise_attention_grads_match_ref(skip):
+    """The training path's gradients, with the running max held constant,
+    equal the dense softmax reference's (multi-block, causal)."""
+    B, S, H, K, D = 2, 96, 4, 2, 32
+    q, k, v = (rand((B, S, n, D), jnp.float32) for n in (H, K, K))
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    got = jax.grad(loss(lambda q, k, v: blockwise_attention(
+        q, k, v, causal=True, scale=D ** -0.5, block_q=32, block_kv=32,
+        skip_masked_blocks=skip)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: attention_ref(
+        q, k, v, causal=True, scale=D ** -0.5)), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4,
+                                   rtol=1e-4)
 
 
 # --------------------------------------------------- morton matmul sweep ----

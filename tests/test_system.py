@@ -35,8 +35,9 @@ def test_serve_driver_runs():
     from repro.launch.serve import main
     out = main(["--arch", "smollm-135m", "--smoke", "--batch", "2",
                 "--prompt-len", "6", "--gen", "6"])
-    assert out.shape == (2, 6)
-    assert not np.isnan(np.asarray(out, dtype=np.float64)).any()
+    assert out["tokens"].shape == (2, 6)
+    assert out["logits"].shape == (2, 1, 256)
+    assert np.isfinite(np.asarray(out["logits"], dtype=np.float32)).all()
 
 
 @pytest.mark.slow

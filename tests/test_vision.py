@@ -8,6 +8,7 @@ from repro.core.cutout import ingest
 from repro.core.store import CuboidStore, MemoryBackend
 from repro.vision import (connected_components, detect_synapses,
                           gaussian_blur, run_parallel_detection)
+from repro.vision.synapse_detector import synapse_mask
 
 
 def test_gaussian_blur_preserves_mean():
@@ -86,3 +87,40 @@ def test_parallel_detection_end_to_end():
     some = ids[0]
     vox = proj.voxel_list(some, 0)
     assert len(vox) >= 4
+
+
+def _detect_per_label_scan(vol, min_voxels=8, max_voxels=512):
+    """Reference: one whole-volume scan per label."""
+    resp, mask = synapse_mask(vol)
+    labels = np.asarray(connected_components(mask))
+    resp_np = np.asarray(resp)
+    dets, out, next_id = [], np.zeros_like(labels), 1
+    for lab in np.unique(labels):
+        if lab == 0:
+            continue
+        where = np.argwhere(labels == lab)
+        n = len(where)
+        if not (min_voxels <= n <= max_voxels):
+            continue
+        conf = float(1.0 / (1.0 + np.exp(-resp_np[tuple(where.T)].mean())))
+        dets.append((tuple(where.mean(axis=0)), n,
+                     tuple(int(v) for v in where.min(axis=0)),
+                     tuple(int(v) for v in where.max(axis=0) + 1), conf))
+        out[tuple(where.T)] = next_id
+        next_id += 1
+    return dets, out
+
+
+def test_detect_synapses_matches_per_label_scan():
+    rng = np.random.default_rng(5)
+    vol = rng.normal(100, 4, size=(48, 40, 12)).astype(np.float32)
+    xx, yy, zz = np.ogrid[:48, :40, :12]
+    for c in rng.integers((4, 4, 2), (44, 36, 10), size=(10, 3)):
+        d2 = (xx - c[0]) ** 2 + (yy - c[1]) ** 2 + ((zz - c[2]) * 2) ** 2
+        vol += 90.0 * np.exp(-d2 / 9.0)
+    dets, labels = detect_synapses(vol)
+    want, want_labels = _detect_per_label_scan(vol)
+    assert len(want) >= 5
+    assert [(d.centroid, d.n_voxels, d.bbox_lo, d.bbox_hi, d.confidence)
+            for d in dets] == want
+    np.testing.assert_array_equal(labels, want_labels)
