@@ -1,0 +1,11 @@
+"""The benchmark's own tests: on the CPU, at sizes a test run holds.
+
+  JAX_PLATFORMS=cpu python3 -m pytest -q perfbench/tests
+"""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
