@@ -868,12 +868,25 @@ class ClusterStore:
     def _serving_job(node: CuboidStore, fn: Callable[[], object], idx: int) -> Callable[[], object]:
         """Wrap a per-node read job so the node's inflight gauge tracks it
         (the signal `_pick_replica` balances on) and a sampled request
-        gets one ``node.fetch`` span per fanned-out node."""
+        gets one ``node.fetch`` span per fanned-out node (``queued_s``: its
+        wait for a pool thread, 0 when it runs in the caller's)."""
 
         def run():
-            with trace.span("node.fetch", node=idx):
+            with trace.span("node.fetch", node=idx, queued_s=0.0):
                 with node.serving():
                     return fn()
+
+        return run
+
+    @staticmethod
+    def _store_job(node: CuboidStore, r: int, blocks: Dict[int, np.ndarray],
+                   channel: int) -> Callable[[], None]:
+        """One node's part of a batch write, as a ``node.store`` span
+        (``queued_s`` as on ``node.fetch``)."""
+
+        def run():
+            with trace.span("node.store", queued_s=0.0):
+                node.store_cuboids(r, blocks, channel)
 
         return run
 
@@ -1232,17 +1245,13 @@ class ClusterStore:
                         by_node.setdefault(node, {})[m] = data
             if by_node:  # non-moving blocks never wait on the move lock
                 jobs = {
-                    node: functools.partial(
-                        topo.nodes[node].store_cuboids, r, node_blocks, channel
-                    )
+                    node: self._store_job(topo.nodes[node], r, node_blocks, channel)
                     for node, node_blocks in by_node.items()
                 }
                 self._fan_out(jobs)
             if doubling:
                 jobs = {
-                    node: functools.partial(
-                        topo.nodes[node].store_cuboids, r, node_blocks, channel
-                    )
+                    node: self._store_job(topo.nodes[node], r, node_blocks, channel)
                     for node, node_blocks in doubling.items()
                 }
                 with self._move_lock:

@@ -18,6 +18,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import trace
 from .cuboid import DatasetSpec
 from .cutout import cutout, write_cutout, build_hierarchy
 from .spatial_index import ObjectIndex
@@ -176,6 +177,12 @@ class AnnotationProject:
                      on_conflict=exc_sink)
         self._dirty_levels.add(r)
         if update_index:
+            self._update_index(r, lo, labels)
+
+    def _update_index(self, r: int, lo: Sequence[int],
+                      labels: np.ndarray) -> None:
+        """Record, for every id in ``labels``, the cuboids it touches."""
+        with trace.span("annotate.index"):
             grid = self.spec.grid(r)
             hi = [l + s for l, s in zip(lo, labels.shape)]
             clo, chi = grid.clamp_box(lo, hi)
@@ -284,11 +291,15 @@ class AnnotationProject:
         batch path shares one index append transaction across objects.
         """
         ids = []
-        for ann, lo, vol in objects:
-            ann = self.meta.create(ann)
-            ids.append(ann.ann_id)
-            vol = np.where(vol != 0, np.uint32(ann.ann_id), 0)
-            self.write(r, lo, vol, discipline=discipline)
+        with trace.span("annotate.batch") as counts:
+            for ann, lo, vol in objects:
+                ann = self.meta.create(ann)
+                ids.append(ann.ann_id)
+                vol = np.where(vol != 0, np.uint32(ann.ann_id), 0)
+                self.write(r, lo, vol, discipline=discipline)
+            if counts is not None:
+                counts["object_voxels"] = sum(
+                    int(np.count_nonzero(vol)) for _, _, vol in objects)
         return ids
 
     def batch_read_objects(self, ann_ids: Sequence[int], r: int):

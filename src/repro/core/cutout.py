@@ -239,45 +239,49 @@ def write_cutout(store: CuboidStore, r: int, lo: Sequence[int],
     with trace.span("write.fetch", runs=len(plan.runs)):
         blobs = store.fetch_runs(r, plan.runs, channel)
     flush_every = 64  # ~16 MB of 256K-voxel uint8 cuboids per chunk
-    out_blocks: Dict[int, np.ndarray] = {}
-    for cell, origin in zip(plan.cells, plan.origins):
-        m = int(cell)
-        blob = blobs.get(m)
-        block = (np.zeros(cs, dtype=dtype) if blob is None
-                 else decompress(blob, cs, dtype).copy())
-        # overlap of this cuboid with the data box, in both frames
-        b_lo = [max(0, l - int(o)) for l, o in zip(clo, origin)]
-        b_hi = [min(c, h - int(o)) for c, h, o in zip(cs, chi, origin)]
-        d_lo = [int(o) + bl - l for o, bl, l in zip(origin, b_lo, lo)]
-        d_hi = [int(o) + bh - l for o, bh, l in zip(origin, b_hi, lo)]
-        bsl = tuple(slice(a, b) for a, b in zip(b_lo, b_hi))
-        dsl = tuple(slice(a, b) for a, b in zip(d_lo, d_hi))
-        new = data[dsl]
-        old = block[bsl]
-        if discipline == "overwrite":
-            merged = np.where(new != 0, new, old)
-        elif discipline == "preserve":
-            merged = np.where(old != 0, old, new)
-        else:  # exception
-            merged = np.where(old != 0, old, new)
-            if on_conflict is not None:
-                conflict = (old != 0) & (new != 0) & (old != new)
-                if conflict.any():
-                    # report in full-cuboid frame so flat voxel offsets
-                    # are stable keys for the exceptions list (§3.2)
-                    old_full = np.zeros(cs, dtype=block.dtype)
-                    new_full = np.zeros(cs, dtype=block.dtype)
-                    old_full[bsl] = old * conflict
-                    new_full[bsl] = new * conflict
-                    on_conflict(m, tuple(int(o) for o in origin),
-                                old_full, new_full)
-        block[bsl] = merged.astype(block.dtype)
-        out_blocks[m] = block
-        if len(out_blocks) >= flush_every:
-            with trace.span("write.store", cuboids=len(out_blocks)):
-                store.store_cuboids(r, out_blocks, channel)
-            out_blocks = {}
-    if out_blocks:
+    cells = list(zip(plan.cells, plan.origins))
+    for c0 in range(0, len(cells), flush_every):
+        chunk = cells[c0:c0 + flush_every]
+        out_blocks: Dict[int, np.ndarray] = {}
+        with trace.span("write.merge") as counts:
+            voxels = 0
+            for cell, origin in chunk:
+                m = int(cell)
+                blob = blobs.get(m)
+                block = (np.zeros(cs, dtype=dtype) if blob is None
+                         else decompress(blob, cs, dtype).copy())
+                # overlap of this cuboid with the data box, in both frames
+                b_lo = [max(0, l - int(o)) for l, o in zip(clo, origin)]
+                b_hi = [min(c, h - int(o)) for c, h, o in zip(cs, chi, origin)]
+                d_lo = [int(o) + bl - l for o, bl, l in zip(origin, b_lo, lo)]
+                d_hi = [int(o) + bh - l for o, bh, l in zip(origin, b_hi, lo)]
+                bsl = tuple(slice(a, b) for a, b in zip(b_lo, b_hi))
+                dsl = tuple(slice(a, b) for a, b in zip(d_lo, d_hi))
+                new = data[dsl]
+                old = block[bsl]
+                voxels += new.size
+                if discipline == "overwrite":
+                    merged = np.where(new != 0, new, old)
+                elif discipline == "preserve":
+                    merged = np.where(old != 0, old, new)
+                else:  # exception
+                    merged = np.where(old != 0, old, new)
+                    if on_conflict is not None:
+                        conflict = (old != 0) & (new != 0) & (old != new)
+                        if conflict.any():
+                            # report in full-cuboid frame so flat voxel
+                            # offsets are stable keys for the exceptions
+                            # list (§3.2)
+                            old_full = np.zeros(cs, dtype=block.dtype)
+                            new_full = np.zeros(cs, dtype=block.dtype)
+                            old_full[bsl] = old * conflict
+                            new_full[bsl] = new * conflict
+                            on_conflict(m, tuple(int(o) for o in origin),
+                                        old_full, new_full)
+                block[bsl] = merged.astype(block.dtype)
+                out_blocks[m] = block
+            if counts is not None:
+                counts["voxels"] = voxels
         with trace.span("write.store", cuboids=len(out_blocks)):
             store.store_cuboids(r, out_blocks, channel)
 

@@ -80,9 +80,6 @@ class DataPipeline:
         self._q: "queue.Queue" = queue.Queue(maxsize=cfg.prefetch)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
-        # instrumentation
-        self.steals = 0
-        self.units_processed = 0
 
     # ---- stateless batch addressing ------------------------------------
     def batch_rows(self, step: int) -> np.ndarray:
@@ -110,26 +107,21 @@ class DataPipeline:
             if len(u):
                 work.put(u)
 
-        def worker(wid: int):
-            local = 0
+        def worker():
             while True:
                 try:
                     u = work.get_nowait()
                 except queue.Empty:
-                    return local
+                    return
                 # visit docs in sorted order -> longer cutout runs (C7)
                 order = np.argsort(rows[u], kind="stable")
                 for k in order:
                     doc = int(rows[u[k]])
                     out[u[k]] = self.store.read_rows(doc, doc + 1, 0, S)[0]
-                local += 1
-                self.units_processed += 1
 
         with cf.ThreadPoolExecutor(max_workers=n_workers) as ex:
-            counts = list(ex.map(worker, range(n_workers)))
-        # steal count: units processed beyond an even share
-        even = n_units // n_workers
-        self.steals += sum(max(0, c - even) for c in counts if c)
+            for f in [ex.submit(worker) for _ in range(n_workers)]:
+                f.result()
         return out
 
     def get_batch(self, step: int) -> Dict[str, np.ndarray]:

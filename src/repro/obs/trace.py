@@ -25,6 +25,25 @@ Propagation: spans cross thread-pool boundaries (node fan-out, decode
 chunks, prefetch tasks) via :func:`bind`, which captures the caller's
 active span and re-installs it inside the worker — a no-op returning the
 original callable when nothing is traced, so pools pay nothing either.
+The first span a bound job opens records, as ``queued_s``, how long the
+job waited between :func:`bind` (taken at ``pool.submit``) and starting
+on a pool thread.
+
+What a sampled span records: its wall time (``dur_s``) and the calling
+thread's CPU time over it (``cpu_s``, ``time.thread_time``), so that work
+can be told from waiting for the interpreter lock or a pool; and whatever
+the stage puts in its meta (the dict ``with span(...) as meta`` yields,
+or :func:`annotate` from code below the span).
+
+One clock with the device: a sampled span also opens a
+``jax.profiler.TraceAnnotation`` under its own name for its lifetime, so
+while ``jax.profiler`` traces (``jax.profiler.start_trace(dir)`` …
+``stop_trace()``) the program's stages appear on the profiler's host
+planes beside the device's operations.  An operator gets both by tracing
+the profiler over requests that are sampled — ``REPRO_TRACE_SAMPLE`` or
+an ``X-Trace-Id`` header — and reads the span tree from the ring
+(``GET /trace/<id>``) and the same stages, by name, in the
+``.xplane.pb``.  Untraced requests put nothing in either.
 """
 
 from __future__ import annotations
@@ -47,6 +66,7 @@ __all__ = [
     "maybe_start",
     "activate",
     "span",
+    "annotate",
     "event",
     "bind",
     "trace_spans",
@@ -126,13 +146,19 @@ class TraceContext:
 
 
 class _Active:
-    """What the context variable holds: (context, innermost open span)."""
+    """What the context variable holds: the context, the innermost open
+    span (its id and meta) and, inside a bound pool job whose first span
+    has not opened yet, how long the job waited in the pool."""
 
-    __slots__ = ("ctx", "span_id")
+    __slots__ = ("ctx", "span_id", "meta", "queued_s")
 
-    def __init__(self, ctx: TraceContext, span_id: int):
+    def __init__(self, ctx: TraceContext, span_id: int,
+                 meta: Optional[Dict[str, Any]] = None,
+                 queued_s: Optional[float] = None):
         self.ctx = ctx
         self.span_id = span_id
+        self.meta = meta
+        self.queued_s = queued_s
 
 
 _current: contextvars.ContextVar[Optional[_Active]] = contextvars.ContextVar(
@@ -227,10 +253,12 @@ class _Span:
 
     ``__enter__`` yields the (mutable) meta dict so stages can annotate
     results discovered mid-span (cache hits, byte counts) without a
-    second record.
+    second record.  For its lifetime the span is also a host annotation
+    in the profiler's trace, under the span's name.
     """
 
-    __slots__ = ("_name", "_meta", "_active", "_sid", "_token", "_t0")
+    __slots__ = ("_name", "_meta", "_active", "_sid", "_token", "_t0",
+                 "_c0", "_ann")
 
     def __init__(self, name: str, meta: Dict[str, Any], active: _Active):
         self._name = name
@@ -239,14 +267,28 @@ class _Span:
         self._sid = next(_span_ids)
         self._token = None
         self._t0 = 0.0
+        self._c0 = 0.0
+        self._ann = None
+        if active.queued_s is not None:  # the first span of a bound job
+            meta["queued_s"] = active.queued_s
+            active.queued_s = None
 
     def __enter__(self) -> Dict[str, Any]:
-        self._token = _current.set(_Active(self._active.ctx, self._sid))
+        self._token = _current.set(
+            _Active(self._active.ctx, self._sid, self._meta))
+        # imported here, so that the untraced path imports nothing
+        from jax.profiler import TraceAnnotation
+
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
+        self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self._meta
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self._t0
+        cpu = time.thread_time() - self._c0
+        self._ann.__exit__(exc_type, exc, tb)
         _current.reset(self._token)
         if exc_type is not None:
             self._meta["error"] = exc_type.__name__
@@ -259,6 +301,7 @@ class _Span:
                 "name": self._name,
                 "t0": self._t0,
                 "dur_s": dur,
+                "cpu_s": cpu,
                 "thread": threading.current_thread().name,
                 "meta": self._meta,
             }
@@ -277,6 +320,15 @@ def span(name: str, **meta: Any):
     if active is None:
         return _NULL
     return _Span(name, meta, active)
+
+
+def annotate(**meta: Any) -> None:
+    """Add results to the innermost open span's meta from code below it
+    (a count the stage's callee learns).  Untraced, or with no span open,
+    it does nothing."""
+    active = _current.get()
+    if active is not None and active.meta is not None:
+        active.meta.update(meta)
 
 
 def event(name: str, **meta: Any) -> None:
@@ -306,14 +358,21 @@ def bind(fn: Callable) -> Callable:
     Returns ``fn`` untouched when nothing is traced — pools on the
     untraced path pay a single ContextVar read per job.  Otherwise the
     wrapper re-installs the capturing span for the duration of the call,
-    so worker-side spans nest under the submitting stage.
+    so worker-side spans nest under the submitting stage, and hands the
+    job's wait since this call to the first span it opens (``queued_s``):
+    call it as the job is submitted, ``pool.submit(bind(job))``.
     """
     active = _current.get()
     if active is None:
         return fn
+    t_bound = time.perf_counter()
 
     def bound(*args, **kwargs):
-        token = _current.set(active)
+        # no meta: annotate() from a pool thread never writes into the
+        # submitting span, which other jobs of the fan-out share
+        token = _current.set(
+            _Active(active.ctx, active.span_id, None,
+                    time.perf_counter() - t_bound))
         try:
             return fn(*args, **kwargs)
         finally:

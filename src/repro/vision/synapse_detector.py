@@ -28,6 +28,7 @@ import numpy as np
 from ..core.annotations import Annotation, AnnotationProject
 from ..core.cutout import cutout
 from ..core.store import CuboidStore
+from ..obs import trace
 
 
 def _gauss_kernel(sigma: float, radius: int) -> jnp.ndarray:
@@ -62,14 +63,15 @@ def difference_of_gaussians(vol, sigma1=(1.0, 1.0, 0.5),
 
 
 @functools.partial(jax.jit, static_argnames=("connectivity",))
-def connected_components(mask: jnp.ndarray,
-                         connectivity: int = 6) -> jnp.ndarray:
+def _connected_components_sweeps(mask: jnp.ndarray, connectivity: int = 6):
     """Label 3-d connected components by iterative min-label propagation.
 
     Each foreground voxel starts with its flat index + 1; every sweep takes
     the min over face neighbors; a `lax.while_loop` runs to fixpoint. On TPU
     this is embarrassingly vectorizable (shifts + minimum) — the adaptation
     of a classically pointer-chasing CPU algorithm to SIMD hardware.
+    Returns the labels and the loop's sweep count (the sweep that finds
+    nothing changed included, the first propagation before the loop not).
     """
     fg = mask != 0
     init = jnp.where(
@@ -97,8 +99,20 @@ def connected_components(mask: jnp.ndarray,
         lab, _, it = state
         return neighbor_min(lab), lab, it + 1
 
-    lab, _, _ = jax.lax.while_loop(
+    lab, _, it = jax.lax.while_loop(
         cond, body, (neighbor_min(init), init, jnp.int32(0)))
+    return lab, it
+
+
+def connected_components(mask: jnp.ndarray,
+                         connectivity: int = 6) -> jnp.ndarray:
+    """Component labels of ``mask`` (see `_connected_components_sweeps`).
+
+    A sampled trace records the sweep count as ``sweeps`` on the enclosing
+    span; untraced, the count is never read back."""
+    lab, sweeps = _connected_components_sweeps(mask, connectivity)
+    if trace.current() is not None:
+        trace.annotate(sweeps=int(sweeps))
     return lab
 
 
@@ -149,33 +163,35 @@ def detect_synapses(vol: np.ndarray, threshold: float = 2.0,
                     exclusion_mask: Optional[np.ndarray] = None
                     ) -> Tuple[List[Detection], np.ndarray]:
     """Detect synapse-like blobs in one cutout. Returns detections + labels."""
-    resp, mask = synapse_mask(vol, threshold, exclusion_mask)
-    labels = np.asarray(connected_components(mask))
+    with trace.span("detect.device"):
+        resp, mask = synapse_mask(vol, threshold, exclusion_mask)
+        labels = np.asarray(connected_components(mask))
+        resp_np = np.asarray(resp)
     dets: List[Detection] = []
     out_labels = np.zeros_like(labels)
-    resp_np = np.asarray(resp)
     next_id = 1
-    # group foreground voxels by label with one stable sort: each group
-    # lists its voxels in C order, as np.argwhere(labels == lab) would,
-    # without a whole-volume scan per label
-    fg = np.flatnonzero(labels)
-    order = np.argsort(labels.ravel()[fg], kind="stable")
-    labs, starts, sizes = np.unique(labels.ravel()[fg][order],
-                                    return_index=True, return_counts=True)
-    for lab, start, n in zip(labs, starts, sizes):
-        if not (min_voxels <= n <= max_voxels):
-            continue  # too small = noise; too big = not a synapse (§3.1)
-        where = np.stack(np.unravel_index(fg[order[start:start + n]],
-                                          labels.shape), axis=1)
-        lo = where.min(axis=0)
-        hi = where.max(axis=0) + 1
-        conf = float(1.0 / (1.0 + np.exp(
-            -resp_np[tuple(where.T)].mean())))
-        dets.append(Detection(tuple(where.mean(axis=0)), int(n),
-                              tuple(int(v) for v in lo),
-                              tuple(int(v) for v in hi), conf))
-        out_labels[tuple(where.T)] = next_id
-        next_id += 1
+    with trace.span("detect.group"):
+        # group foreground voxels by label with one stable sort: each group
+        # lists its voxels in C order, as np.argwhere(labels == lab) would,
+        # without a whole-volume scan per label
+        fg = np.flatnonzero(labels)
+        order = np.argsort(labels.ravel()[fg], kind="stable")
+        labs, starts, sizes = np.unique(labels.ravel()[fg][order],
+                                        return_index=True, return_counts=True)
+        for lab, start, n in zip(labs, starts, sizes):
+            if not (min_voxels <= n <= max_voxels):
+                continue  # too small = noise; too big = not a synapse (§3.1)
+            where = np.stack(np.unravel_index(fg[order[start:start + n]],
+                                              labels.shape), axis=1)
+            lo = where.min(axis=0)
+            hi = where.max(axis=0) + 1
+            conf = float(1.0 / (1.0 + np.exp(
+                -resp_np[tuple(where.T)].mean())))
+            dets.append(Detection(tuple(where.mean(axis=0)), int(n),
+                                  tuple(int(v) for v in lo),
+                                  tuple(int(v) for v in hi), conf))
+            out_labels[tuple(where.T)] = next_id
+            next_id += 1
     return dets, out_labels
 
 

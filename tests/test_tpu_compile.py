@@ -25,7 +25,7 @@ from repro.kernels.flash_decode.ops import flash_decode
 from repro.models import build_model
 from repro.models.params import tree_map_specs
 from repro.serve import make_serve_step
-from repro.vision.synapse_detector import connected_components
+from repro.vision.synapse_detector import _connected_components_sweeps
 
 
 @pytest.fixture(scope="module")
@@ -75,8 +75,10 @@ def test_cutout_gather_compiles_unaligned_box(one_chip):
 
 def test_connected_components_compiles(one_chip):
     mask = _sds((512, 512, 16), "bool", one_chip)
-    compiled = connected_components.lower(mask).compile()
-    assert compiled.memory_analysis().output_size_in_bytes == 512 * 512 * 16 * 4
+    compiled = _connected_components_sweeps.lower(mask).compile()
+    # the int32 labels, and the int32 sweep count in one 1 KiB padded buffer
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert out == 512 * 512 * 16 * 4 + 1024
 
 
 def test_smollm_serve_step_compiles(one_chip):
