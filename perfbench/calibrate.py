@@ -14,13 +14,10 @@ do this.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
-import os
 import sys
 import time
-from pathlib import Path
-
-ROOT = Path(__file__).resolve().parents[1]
 
 
 def main(argv=None) -> int:
@@ -32,17 +29,11 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
 
-    from run import CACHE_DIR
-    CACHE_DIR.mkdir(exist_ok=True)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
-    sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-    import contextlib
+    from run import configure_jax
+    configure_jax()
 
-    import jax
-    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-
-    from perfbench.lib import faults, harness
+    from perfbench.lib import harness
+    from perfbench.tests import cellfiles
     bench = harness.load_benchmark()
     cell, _, config, traffic = harness.find_cell(bench, args.workload)
     device = harness.device_info(cell["chips"])
@@ -51,8 +42,8 @@ def main(argv=None) -> int:
     try:
         for seed in [int(s) for s in args.seeds.split(",")]:
             t0 = time.perf_counter()
-            planted = (faults.FAULTS[args.fault]() if args.fault
-                       else contextlib.nullcontext())
+            planted = (cellfiles.fault(bench, args.workload, args.fault)()
+                       if args.fault else contextlib.nullcontext())
             driver = kind.Cell(config, traffic, seed)
             try:
                 with planted:

@@ -1,4 +1,9 @@
-"""Training a dense decoder with ``launch/train.py``'s parts.
+"""Training a decoder with ``launch/train.py``'s parts.
+
+The architecture comes from the configuration: the stem of its
+``"reference"`` names ``perfbench/archs/<stem>.py``, which gives the
+program's ``ModelConfig``, the parameter tree, the plain reference and the
+step's FLOPs (see ``archs/llama.py``).
 
 Set-up builds one object, the compiled step (``make_train_step`` under
 ``jax.jit`` with the state donated, as ``launch/train.py`` builds it) with
@@ -15,43 +20,43 @@ finished.
 """
 from __future__ import annotations
 
+import functools
+import importlib
 import time
-from typing import Dict, List
+from pathlib import Path
+from typing import Dict, FrozenSet, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..lib import lm_data, xtrace
-from ..lib.harness import Check, Window, log
-from ..refs import llama as ref
+from ..lib import hlo_scopes, lm_data, xtrace
+from ..lib.harness import BENCH_DIR, Check, Window, log
 
 CHECK_STEPS = 3
 # steps the host may dispatch before the oldest of them has finished
 AHEAD = 2
 
 
-def model_config(cfg: Dict):
-    """The program's ``ModelConfig`` for a Llama-architecture file."""
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        act={"silu": "swiglu"}[cfg["hidden_act"]],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        dtype=cfg["torch_dtype"])
+def architecture(cfg: Dict):
+    """The module of ``perfbench/archs/`` named by the stem of the
+    configuration's ``"reference"``."""
+    stem = Path(cfg["reference"]).stem
+    if not (BENCH_DIR / "archs" / f"{stem}.py").is_file():
+        raise FileNotFoundError(
+            f"configuration {cfg.get('name')!r} names the reference "
+            f"{cfg['reference']!r}, and there is no "
+            f"{BENCH_DIR / 'archs' / (stem + '.py')}")
+    return importlib.import_module(f"..archs.{stem}", __package__)
 
 
-def leaf_norms(flat: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
-    """Norm of every leaf, each layer of a stacked leaf on its own."""
+def leaf_norms(flat: Dict[str, jax.Array],
+               stacked: FrozenSet[str]) -> Dict[str, jax.Array]:
+    """Norm of every leaf, each layer of a ``stacked`` leaf on its own."""
     out = {}
     for path, x in flat.items():
         x = x.astype(jnp.float32)
-        if path.startswith("blocks/"):
+        if path in stacked:
             n = jnp.sqrt(jnp.sum(x * x, axis=tuple(range(1, x.ndim))))
             for i in range(x.shape[0]):
                 out[f"{path}[{i}]"] = n[i]
@@ -60,13 +65,13 @@ def leaf_norms(flat: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
     return out
 
 
-_norms = jax.jit(leaf_norms)
+_norms = jax.jit(leaf_norms, static_argnums=1)
 
 
-@jax.jit
-def _change_norms(master: Dict, init: Dict) -> Dict:
+@functools.partial(jax.jit, static_argnums=2)
+def _change_norms(master: Dict, init: Dict, stacked: FrozenSet[str]) -> Dict:
     return leaf_norms({k: master[k] - init[k].astype(jnp.float32)
-                       for k in master})
+                       for k in master}, stacked)
 
 
 @jax.jit
@@ -115,7 +120,14 @@ class Cell:
     def __init__(self, config: Dict, traffic: Dict, seed: int):
         self.config, self.traffic, self.seed = config, traffic, seed
         self.batch, self.seq = traffic["batch"], traffic["seq_len"]
+        self.arch = architecture(config)
         self.input_s: List[float] = []
+        self.window_metrics: List[Dict[str, jax.Array]] = []
+        self._split = None
+
+    def _params(self) -> Dict:
+        return lm_data.make_params(self.arch.param_shapes(self.config),
+                                   self.config["torch_dtype"], self.seed)
 
     # ------------------------------------------------------------ set-up ----
     def setup(self) -> None:
@@ -128,12 +140,15 @@ class Cell:
 
         cfg, tr = self.config, self.traffic
         t0 = time.perf_counter()
-        self.mcfg = model_config(cfg)
+        self.mcfg = self.arch.model_config(cfg)
         model = build_model(self.mcfg)
         specs = model.specs()
+        self.stacked = frozenset(
+            path for path, spec in lm_data.flatten(specs).items()
+            if spec.axes[:1] == ("layers",))
         self.mesh = make_device_mesh(jax.devices()[:1])
         self.plan = make_plan(self.mesh)
-        params = lm_data.make_params(cfg, self.seed)
+        params = self._params()
         want = jax.tree.map(lambda s: (tuple(s.shape), s.dtype), specs,
                             is_leaf=lambda s: hasattr(s, "axes"))
         got = jax.tree.map(lambda a: (tuple(a.shape), str(a.dtype)), params)
@@ -169,10 +184,11 @@ class Cell:
                 b1 = self.opt_cfg.b1
                 self.grad_norms = _norms({
                     k: v / (1 - b1)
-                    for k, v in lm_data.flatten(state[1]["mu"]).items()})
-        init = lm_data.flatten(lm_data.make_params(cfg, self.seed))
+                    for k, v in lm_data.flatten(state[1]["mu"]).items()},
+                    self.stacked)
+        init = lm_data.flatten(self._params())
         self.change_norms = _change_norms(
-            lm_data.flatten(state[1]["master"]), init)
+            lm_data.flatten(state[1]["master"]), init, self.stacked)
         del init
         self.losses = [float(x) for x in self.losses]
         self.grad_norms = {k: float(v) for k, v in self.grad_norms.items()}
@@ -211,6 +227,11 @@ class Cell:
                 self.mesh, batch_pspec(self.plan, v.ndim, v.shape[0])))
                 for k, v in batch.items()}
         self.input_s.append(time.perf_counter() - t0)
+        if step == CHECK_STEPS - 1:     # arguments shaped as the window's
+            self.step_args = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=a.sharding),
+                (*state, batch))
         with xtrace.span("step"), self.use_plan(self.plan):
             params, opt, metrics = self.step_fn(*state, batch)
         return (params, opt), metrics
@@ -222,6 +243,7 @@ class Cell:
         t0 = time.perf_counter()
         while time.perf_counter() - t0 < seconds:
             state, metrics = self._step(state, self.next_step + n)
+            self.window_metrics.append(metrics)
             n += 1
             # the host runs at most AHEAD steps in front of the device
             pending.append(metrics["loss"])
@@ -248,6 +270,43 @@ class Cell:
         self.state = None
         self.pipe.stop()
 
+    def counters(self) -> Dict[str, float]:
+        """The mean over the window's steps of each scalar the step returns
+        (its loss, and any count an architecture's FLOPs depend on)."""
+        got = jax.device_get(self.window_metrics)
+        return {k: float(np.mean([m[k] for m in got])) for k in got[0]
+                if np.ndim(got[0][k]) == 0} if got else {}
+
+    def step_hlo(self) -> Tuple[str, str]:
+        """The step's module name and compiled text: lowered again for the
+        window's arguments, and taken from the compilation cache."""
+        with self.use_plan(self.plan):
+            text = self.step_fn.lower(*self.step_args).compile().as_text()
+        return text.split(None, 2)[1].rstrip(","), text
+
+    def layer_split(self, trace) -> Tuple[Dict[Tuple[str, str], float], int]:
+        """Device seconds of the traced window by (layer, pass) (see
+        ``hlo_scopes``), and the step's calls in it. Read after the window,
+        so that the step's text is fetched outside set-up and window."""
+        if self._split is None:
+            module, text = self.step_hlo()
+            ops = hlo_scopes.parse(text)
+            split = hlo_scopes.split(trace.op_s, {module: ops},
+                                     self.arch.SCOPES, self.arch.LAYERS)
+            steps = trace.module_seconds(module)[1]
+            self._split = split, steps
+            mine = {k.split(":", 1)[1]: v for k, v in trace.op_s.items()
+                    if k.startswith(module + ":")}
+            named = sum(v for k, v in mine.items() if k in ops)
+            per = max(steps, 1)
+            log(f"device ms a step by layer and pass ({steps} steps of "
+                f"{module}; busy {1e3 * trace.busy_s / per:.3f}; the text "
+                f"names {named / max(sum(mine.values()), 1e-12):.4f} of the "
+                f"step's time): " + ", ".join(
+                    f"{k[0]}.{k[1]} {1e3 * v / per:.3f}"
+                    for k, v in sorted(split.items())))
+        return self._split
+
     # ------------------------------------------------------------- check ----
     def reference(self, precision: str = "f32"):
         """The reference's three steps on the rows that were fed, taken from
@@ -262,14 +321,14 @@ class Cell:
             rows = [0 if r is None else r for r in rows]
             full = self.corpus[rows, :self.seq + 1]
             batches.append((full[:, :-1], full[:, 1:]))
-        params = jax.tree.map(lambda a: a.astype(jnp.float32),
-                              lm_data.make_params(self.config, self.seed))
-        losses, grad, final = ref.train(self.config, self.config["optimizer"],
-                                        params, batches, precision)
+        params = jax.tree.map(lambda a: a.astype(jnp.float32), self._params())
+        losses, grad, final = self.arch.reference.train(
+            self.config, self.config["optimizer"], params, batches, precision)
         grad_n = {k: float(v) for k, v in
-                  _norms(lm_data.flatten(grad)).items()}
+                  _norms(lm_data.flatten(grad), self.stacked).items()}
         change_n = {k: float(v) for k, v in _change_norms(
-            lm_data.flatten(final), lm_data.flatten(params)).items()}
+            lm_data.flatten(final), lm_data.flatten(params),
+            self.stacked).items()}
         return losses, grad_n, change_n
 
     def program(self):

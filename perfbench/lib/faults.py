@@ -2,12 +2,15 @@
 comparison and for the chip readings of the training cell's numbers.
 
 Each is a context manager that patches one program function for the
-duration of a run; the benchmark's own runs never use them.
+duration of a run; the benchmark's own runs never use them. A cell lists
+its faults by name in its test file (``perfbench/tests/cells/``); ``find``
+looks a name up in ``FAULTS`` here and in the ``FAULTS`` dicts of the
+modules a cell brings.
 """
 from __future__ import annotations
 
 import contextlib
-from typing import Iterator
+from typing import Callable, Iterator, Sequence
 
 import numpy as np
 
@@ -120,3 +123,13 @@ FAULTS = {
     "detect_one_replica": detect_one_replica,
     "detect_no_exclusion": detect_no_exclusion,
 }
+
+
+def find(name: str, modules: Sequence = ()) -> Callable:
+    """Fault ``name`` from ``FAULTS`` or from a ``FAULTS`` dict of one of
+    ``modules``."""
+    for table in [FAULTS] + [getattr(m, "FAULTS", {}) for m in modules]:
+        if name in table:
+            return table[name]
+    raise KeyError(f"no fault {name!r} in perfbench/lib/faults.py or in "
+                   f"{[m.__name__ for m in modules]}")

@@ -1,7 +1,8 @@
 """Seeded inputs of the model cells: weights and token corpora.
 
 The weights are made on the device in one jitted call from the seed, in the
-type they are served in, and laid out as the program's parameter tree. The
+type they are served in, and laid out as the program's parameter tree (its
+shapes come from the cell's architecture, ``perfbench/archs/``). The
 reference regenerates them from the same seed; it takes nothing the
 program made. The corpus is Zipf-distributed token ids (p(i) ~ 1/(i+1)),
 drawn on the host by inverse CDF.
@@ -23,28 +24,6 @@ def key_from_seed(seed: int, stream: int = 0) -> jax.Array:
     words = np.random.SeedSequence([seed, stream]).generate_state(2)
     return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32),
                                     impl="threefry2x32")
-
-
-def param_shapes(cfg: Dict) -> Dict[str, Tuple[Tuple[int, ...], str]]:
-    """Flat {path: (shape, kind)} of the program's dense-LM parameter tree;
-    kind is "matrix" (normal, served dtype) or "norm" (ones, float32)."""
-    L, d, f = cfg["num_hidden_layers"], cfg["hidden_size"], \
-        cfg["intermediate_size"]
-    H, K, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], \
-        cfg["head_dim"]
-    return {
-        "embed": ((cfg["vocab_size"], d), "matrix"),
-        "final_norm": ((d,), "norm"),
-        "blocks/ln1": ((L, d), "norm"),
-        "blocks/attn/w_q": ((L, d, H * hd), "matrix"),
-        "blocks/attn/w_k": ((L, d, K * hd), "matrix"),
-        "blocks/attn/w_v": ((L, d, K * hd), "matrix"),
-        "blocks/attn/w_o": ((L, H * hd, d), "matrix"),
-        "blocks/ln2": ((L, d), "norm"),
-        "blocks/mlp/w_gate": ((L, d, f), "matrix"),
-        "blocks/mlp/w_up": ((L, d, f), "matrix"),
-        "blocks/mlp/w_down": ((L, f, d), "matrix"),
-    }
 
 
 def unflatten(flat: Dict[str, jax.Array]) -> Dict:
@@ -82,12 +61,14 @@ def _make(shapes, key, dtype):
     return out
 
 
-def make_params(cfg: Dict, seed: int) -> Dict:
-    """The parameter tree from ``seed``: matrices drawn N(0, 0.02) in
-    float32 and rounded to the served dtype, norms ones in float32."""
-    shapes = tuple(sorted(param_shapes(cfg).items()))
-    return unflatten(_make(shapes, key_from_seed(seed),
-                           jnp.dtype(cfg["torch_dtype"]).name))
+def make_params(shapes: Dict[str, Tuple[Tuple[int, ...], str]], dtype: str,
+                seed: int) -> Dict:
+    """The parameter tree of flat ``{path: (shape, kind)}`` (an
+    architecture's ``param_shapes``) from ``seed``: "matrix" leaves drawn
+    N(0, 0.02) in float32 and rounded to ``dtype``, "norm" leaves ones in
+    float32."""
+    return unflatten(_make(tuple(sorted(shapes.items())), key_from_seed(seed),
+                           jnp.dtype(dtype).name))
 
 
 def zipf_tokens(seed: int, shape: Tuple[int, ...], vocab: int,

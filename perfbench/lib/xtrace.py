@@ -6,6 +6,9 @@ its own window (``--trace 1``) and reduces the trace in the same process:
 - busy seconds: the union of the device's operation intervals inside the
   window, averaged over the device planes;
 - per-module device time: the ``XLA Modules`` events summed by name;
+- per-operation device time: each instant counted once, for the innermost
+  operation running then (a loop's ``while`` gets only what its body's
+  operations leave uncovered), summed by ``module:op``;
 - ``breakdown``: the device operations that took most time, and the idle
   time between operations attributed to the host span (``bench.*``
   annotations written by the benchmark's own wrappers) that covers most of
@@ -50,7 +53,7 @@ class TraceSummary:
     devices: int
     module_s: Dict[str, float]          # device seconds per module name
     module_calls: Dict[str, int]
-    op_s: Dict[str, float]              # device seconds per "module:op"
+    op_s: Dict[str, float]              # innermost device s per "module:op"
     idle_by_host: Dict[str, float]      # idle seconds per host span label
 
     @property
@@ -110,6 +113,33 @@ def _covered(merged: np.ndarray, a: np.ndarray, b: np.ndarray) -> np.ndarray:
     return upto(b) - upto(a)
 
 
+def self_times(events) -> Dict[str, float]:
+    """Length of time each ``(start, end, name)`` event is the innermost one
+    running, summed by name: every covered instant counts once, for the
+    event that started last of those covering it."""
+    out: Dict[str, float] = {}
+    stack: List[Tuple[float, str]] = []       # (end, name), by start
+    t = -np.inf
+
+    def run_until(limit: float) -> None:
+        nonlocal t
+        while stack and t < limit:
+            end, name = stack[-1]
+            if end <= t:
+                stack.pop()
+                continue
+            upto = min(end, limit)
+            out[name] = out.get(name, 0.0) + (upto - t)
+            t = upto
+
+    for a, b, name in sorted(events, key=lambda e: (e[0], -e[1])):
+        run_until(a)
+        t = a
+        stack.append((b, name))
+    run_until(np.inf)
+    return out
+
+
 def _is_device(plane_name: str) -> bool:
     return plane_name.startswith("/device:") and "CPU" not in plane_name
 
@@ -164,15 +194,16 @@ def reduce_profile(planes) -> TraceSummary:
     busy_ns = 0.0
     gaps: List[np.ndarray] = []
     op_s: Dict[str, float] = {}
-    by_plane: Dict[str, List[Tuple[float, float]]] = {}
+    by_plane: Dict[str, List[Tuple[float, float, str]]] = {}
     for plane, a, b, name in device_ops:
         lo, hi = max(a, w0), min(b, w1)
         if hi <= lo:
             continue
-        by_plane.setdefault(plane, []).append((lo, hi))
-        op_s[name] = op_s.get(name, 0.0) + (hi - lo) * 1e-9
-    for iv in by_plane.values():
-        merged = _merge(np.asarray(iv, dtype=np.float64))
+        by_plane.setdefault(plane, []).append((lo, hi, name))
+    for events in by_plane.values():
+        for name, ns in self_times(events).items():
+            op_s[name] = op_s.get(name, 0.0) + ns * 1e-9
+        merged = _merge(np.asarray([e[:2] for e in events], dtype=np.float64))
         busy_ns += float((merged[:, 1] - merged[:, 0]).sum())
         edges = np.concatenate([[w0], merged.ravel(), [w1]]).reshape(-1, 2)
         gaps.append(edges[edges[:, 1] > edges[:, 0]])

@@ -1,7 +1,8 @@
 """Model FLOP utilisation of the traced training window: forward and
-backward FLOPs per token (6 per matrix-product parameter plus the causal
-attention products; recomputation not counted) times the window's tokens
-per second, over the chip's bf16 peak."""
+backward FLOPs per token, from the cell's architecture (for a dense model,
+6 per matrix-product parameter plus the causal attention products;
+recomputation not counted), times the window's tokens per second, over the
+chip's bf16 peak."""
 from perfbench.lib import roofline
 
 
@@ -10,5 +11,6 @@ def read(ctx):
     if peak is None or not drv.window_steps:
         return None
     tok_s = drv.window_steps * drv.batch * drv.seq / drv.window_s
-    flops = roofline.llama_train_flops_per_token(ctx["config"], drv.seq)
+    flops = drv.arch.train_flops_per_token(ctx["config"], drv.seq,
+                                           drv.counters())
     return roofline.mfu_pct(flops, tok_s, peak)

@@ -30,6 +30,23 @@ ROOT = Path(__file__).resolve().parents[1]
 CACHE_DIR = ROOT / ".perfbench_cache"
 
 
+def configure_jax() -> None:
+    """JAX's settings for every process of the benchmark, before its first
+    compile."""
+    CACHE_DIR.mkdir(exist_ok=True)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
+    sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # the compiled programs' metadata keeps each operation's name stack and
+    # whole Python stack, from which ``lib/hlo_scopes.py`` gives device time
+    # to layers (the compilation cache's key leaves metadata out, so every
+    # process that writes the cache has to set this alike)
+    jax.config.update("jax_include_full_tracebacks_in_locations", True)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -38,14 +55,7 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=[0, 1], default=0)
     args = ap.parse_args(argv)
 
-    CACHE_DIR.mkdir(exist_ok=True)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
-    sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-    import jax
-    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    configure_jax()
 
     from perfbench.lib import harness
     bench = harness.load_benchmark()

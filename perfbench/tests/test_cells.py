@@ -9,8 +9,8 @@ import time
 
 import pytest
 
-from perfbench.lib import faults, harness
-from perfbench.tests.tiny import OVERRIDES
+from perfbench.lib import harness
+from perfbench.tests import cellfiles
 
 BENCH = harness.load_benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -19,7 +19,7 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 def run(workload, seconds=3.0, seed=2**31 + 77):
     return harness.run_cell(BENCH, workload, seed, seconds, False,
                             time.perf_counter(), require_tpu=False,
-                            overrides=OVERRIDES[workload])
+                            overrides=cellfiles.overrides(workload))
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -37,26 +37,34 @@ def test_a_sound_run_is_correct(workload):
 def test_the_job_takes_the_volume_again_after_its_last_tile():
     """Eight tiles, three workers: the window outlasts a pass."""
     small = {"config": {"volume_shape": [256, 256, 32]},
-             "traffic": {**OVERRIDES["em.detect"]["traffic"]}}
+             "traffic": cellfiles.load("em.detect")["traffic"]}
     r = harness.run_cell(BENCH, "em.detect", 5, 3.0, False, time.perf_counter(),
                          require_tpu=False, overrides=small)
     assert r["correct"], r["checks"]
     assert r["attempted"] > 8
 
 
-FAULTED = [("em.detect", "detect_altered"),
-           ("em.detect", "detect_half_written"),
-           ("em.detect", "detect_one_replica"),
-           ("em.detect", "detect_no_exclusion"),
-           ("smollm.train", "train_half_batch"),
-           ("smollm.train", "train_unchanged")]
-
-
-@pytest.mark.parametrize("workload,fault", FAULTED)
+@pytest.mark.parametrize("workload,fault", cellfiles.pairs(BENCH))
 def test_a_fault_in_the_timed_path_is_not_correct(workload, fault):
-    with faults.FAULTS[fault]():
+    with cellfiles.fault(BENCH, workload, fault)():
         r = run(workload)
     assert not r["correct"], r["checks"]
+
+
+def test_every_cell_lists_the_faults_it_can_have():
+    """Each cell's file names at least one fault, and every name is
+    found."""
+    for cell in CELLS:
+        names = cellfiles.load(cell)["faults"]
+        assert names, cell
+        for name in names:
+            assert callable(cellfiles.fault(BENCH, cell, name))
+
+
+def test_a_cell_without_its_test_file_names_the_file():
+    with pytest.raises(FileNotFoundError) as e:
+        cellfiles.load("no.such.cell")
+    assert str(cellfiles.path("no.such.cell")) in str(e.value)
 
 
 def test_no_chip_is_refused():

@@ -6,7 +6,7 @@ at the cells' own sizes, from which the limits were set, are in PERF.md."""
 import pytest
 
 from perfbench.lib import harness
-from perfbench.tests.tiny import OVERRIDES as SIZES
+from perfbench.tests import cellfiles
 
 BENCH = harness.load_benchmark()
 
@@ -14,8 +14,9 @@ BENCH = harness.load_benchmark()
 @pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
 def test_the_control_reads_over_a_limit_and_the_program_under_all(workload):
     _, _, config, traffic = harness.find_cell(BENCH, workload)
-    config = {**config, **SIZES[workload]["config"]}
-    traffic = {**traffic, **SIZES[workload]["traffic"]}
+    sizes = cellfiles.overrides(workload)
+    config = {**config, **sizes["config"]}
+    traffic = {**traffic, **sizes["traffic"]}
     driver = harness.load_kind(traffic["kind"]).Cell(config, traffic, 11)
     try:
         driver.setup()

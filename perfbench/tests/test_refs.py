@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench.kinds.lm_train import model_config
+from perfbench.archs import llama as arch
 from perfbench.lib import lm_data
 from perfbench.lib.volume import make_volume
 from perfbench.refs import llama, vision
@@ -15,6 +15,11 @@ TINY = {"name": "tiny", "hidden_size": 64, "intermediate_size": 128,
         "hidden_act": "silu", "tie_word_embeddings": True,
         "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
         "torch_dtype": "float32"}
+
+
+def make_params(cfg, seed):
+    return lm_data.make_params(arch.param_shapes(cfg), cfg["torch_dtype"],
+                               seed)
 
 
 def tile():
@@ -55,9 +60,9 @@ def test_partition_mismatch_counts_split_merged_and_moved_voxels():
 def test_llama_reference_matches_the_program_forward_and_gradient():
     from repro.models import build_model
     from repro.train.train_step import loss_fn
-    mcfg = model_config(TINY)
+    mcfg = arch.model_config(TINY)
     model = build_model(mcfg)
-    params = lm_data.make_params(TINY, 5)
+    params = make_params(TINY, 5)
     toks = lm_data.zipf_tokens(5, (2, 33), TINY["vocab_size"])
     tokens, labels = toks[:, :-1], toks[:, 1:]
     got, _ = model.forward(params, jnp.asarray(tokens))
@@ -78,7 +83,7 @@ def test_adamw_reference_matches_the_program_update():
     opt = {"lr_peak": 3e-3, "warmup_steps": 5, "total_steps": 100,
            "b1": 0.9, "b2": 0.95, "eps": 1e-8, "weight_decay": 0.1,
            "clip_norm": 1.0}
-    params = lm_data.make_params(TINY, 6)
+    params = make_params(TINY, 6)
     grads = jax.tree.map(lambda p: jnp.full_like(p, 0.5), params)
     state = {"params": params, "mu": jax.tree.map(jnp.zeros_like, params),
              "nu": jax.tree.map(jnp.zeros_like, params)}

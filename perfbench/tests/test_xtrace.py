@@ -45,8 +45,12 @@ def test_busy_is_the_union_inside_the_window():
 def test_modules_and_ops_are_summed_by_name():
     s = xtrace.reduce_profile(fake_trace())
     assert s.module_seconds("step") == (pytest.approx(400e-9), 2)
-    assert s.op_s["jit_step:fusion.1"] == pytest.approx(200e-9)
+    # fusion.2 starts inside fusion.1: each instant counts once, for the
+    # operation that started last
+    assert s.op_s["jit_step:fusion.1"] == pytest.approx(150e-9)
+    assert s.op_s["jit_step:fusion.2"] == pytest.approx(150e-9)
     assert s.op_s["jit_step:copy.3"] == pytest.approx(100e-9)
+    assert sum(s.op_s.values()) == pytest.approx(s.busy_s)
 
 
 def test_idle_gaps_go_to_the_host_span_that_covers_them():
